@@ -142,7 +142,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as PS
 from repro.core.backends import PallasDmaBackend
 from repro.core.runtime import TrafficLedger
@@ -168,8 +167,8 @@ for opname in ("read", "write"):
         r, nb = prog(sq(b), sq(t), sq(i), sq(v))
         return jnp.expand_dims(r, 0), jnp.expand_dims(nb, 0)
 
-    sm = shard_map(f, mesh=mesh, in_specs=PS("nodes"),
-                   out_specs=PS("nodes"), check_rep=False)
+    sm = jax.shard_map(f, mesh=mesh, in_specs=PS("nodes"),
+                       out_specs=PS("nodes"), check_vma=False)
     rng = np.random.default_rng(0)
     buf = jnp.asarray(rng.integers(0, 99, (P, S, W)).astype(np.int32))
     # saturated + unique: every lane remote (next neighbour), distinct rows
@@ -199,6 +198,9 @@ def _hlo_probe(csv: Csv):
         os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
+    # the probe runs on 8 fake host devices; the parent may already hold
+    # the accelerator, which a second process cannot open
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", _PROBE_SRC], env=env,
                           capture_output=True, text=True, timeout=560)
     assert proc.returncode == 0, \

@@ -44,6 +44,10 @@ def main() -> None:
     args = ap.parse_args()
     want = set(args.only.split(",")) if args.only else None
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+
     from .common import BenchJson, Csv
     csv = Csv()
     print("name,us_per_call,derived")

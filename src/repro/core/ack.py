@@ -31,24 +31,6 @@ from typing import Any, NamedTuple, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-# ---------------------------------------------------------------------------
-# Compat: older jax releases ship no vmap batching rule for
-# ``optimization_barrier``, which breaks every fence/join under the vmap
-# (single-process) runtime binding.  The barrier is identity on each operand,
-# so batching is the primitive applied to the batched operands with the batch
-# dims passed through unchanged.  Registered only when missing.
-try:  # pragma: no cover - exercised implicitly by every vmapped fence
-    from jax._src.lax.lax import optimization_barrier_p as _opt_barrier_p
-    from jax.interpreters import batching as _batching
-
-    if _opt_barrier_p not in _batching.primitive_batchers:
-        def _opt_barrier_batcher(args, dims):
-            return _opt_barrier_p.bind(*args), dims
-
-        _batching.primitive_batchers[_opt_barrier_p] = _opt_barrier_batcher
-except (ImportError, AttributeError):  # newer jax: rule exists, private
-    pass                               # paths moved — nothing to patch.
-
 
 class FenceScope(enum.IntEnum):
     """Fence scopes, weakest to strongest (paper §5.3)."""

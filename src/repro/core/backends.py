@@ -274,9 +274,8 @@ class PallasDmaBackend(CollsBackend):
     with a :class:`_DmaEngine`, which swaps the wire path's jnp
     serve/commit for the :mod:`repro.kernels.remote_dma` kernels —
     descriptor build on the requester, row gather/scatter on the home —
-    while the inter-participant hop stays the XLA collective on the
-    emulation substrate (``pltpu.make_async_remote_copy`` send/wait
-    pairs take over on TPU hardware; see ``remote_copy_tpu``).  Values
+    while the inter-participant hop is the XLA collective on every
+    substrate, TPU included.  Values
     are bitwise those of the one-sided backend — the conformance suite
     pins it — and the scalar verbs route through the R=1 batch path so
     every verb rides the kernels.

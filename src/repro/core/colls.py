@@ -30,19 +30,6 @@ import jax
 import jax.numpy as jnp
 
 
-def axis_size(axis: str) -> int:
-    """Static size of a named axis (vmap or shard_map binding), across the
-    jax 0.4 → 0.5+ API (``jax.lax.axis_size`` is new; 0.4.x exposes the
-    size through the axis frame)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    from jax import core
-    # late 0.4 releases return the size directly; earlier ones return an
-    # AxisEnvFrame whose .size carries it
-    frame = core.axis_frame(axis)
-    return getattr(frame, "size", frame)
-
-
 def my_id(axis: str):
     return jax.lax.axis_index(axis)
 
@@ -349,7 +336,7 @@ def remote_read_coalesced(local_buf, targets, indices, axis: str, preds=None,
     # first enabled remote lane addressing that row; lane i's
     # representative is table[lid_i], and i leads iff that is i itself.
     slots = local_buf.shape[0]
-    n_rows = axis_size(axis) * slots
+    n_rows = jax.lax.axis_size(axis) * slots
     order = jnp.arange(R, dtype=jnp.int32)
     lid = targets * slots + jnp.clip(indices, 0, slots - 1)
     table = jnp.full((n_rows,), R, jnp.int32).at[

@@ -75,14 +75,10 @@ class Runtime:
             out = fn(*squeezed)
             return jax.tree.map(lambda x: jnp.expand_dims(jnp.asarray(x), 0), out)
 
-        kwargs = dict(mesh=self.mesh,
-                      in_specs=jax.tree.map(lambda _: spec, args),
-                      out_specs=spec)
-        if hasattr(jax, "shard_map"):                    # jax >= 0.5
-            shmapped = jax.shard_map(local_fn, check_vma=False, **kwargs)
-        else:                                            # jax 0.4.x
-            from jax.experimental.shard_map import shard_map
-            shmapped = shard_map(local_fn, check_rep=False, **kwargs)
+        shmapped = jax.shard_map(
+            local_fn, mesh=self.mesh,
+            in_specs=jax.tree.map(lambda _: spec, args), out_specs=spec,
+            check_vma=False)
         return shmapped(*args)
 
     # -- helpers used by channel code (inside the per-participant trace) ----
@@ -123,10 +119,13 @@ class TrafficLedger:
 
     Under the vmap binding the callback fires once per participant, so
     totals are cluster-wide wire bytes (each participant accounts its own
-    outgoing lanes exactly once).
+    outgoing lanes exactly once).  The runtime may run those callbacks on
+    several threads at once, so every update holds ``_lock``: an
+    unguarded ``+=`` would lose increments depending on thread timing.
     """
 
     def __init__(self):
+        self._lock = threading.Lock()
         self.enabled = False
         self.counts: Dict[str, Dict[str, float]] = {}
         # modeled collective-round counters (DESIGN.md §14), keyed by verb
@@ -177,9 +176,11 @@ class TrafficLedger:
         before calling so disabled ledgers never emit callbacks.
         """
         def _cb(b, verb=verb):
-            entry = self.counts.setdefault(verb, {"calls": 0, "bytes": 0.0})
-            entry["calls"] += 1
-            entry["bytes"] += float(b)
+            with self._lock:
+                entry = self.counts.setdefault(
+                    verb, {"calls": 0, "bytes": 0.0})
+                entry["calls"] += 1
+                entry["bytes"] += float(b)
 
         jax.debug.callback(_cb, jnp.asarray(wire_bytes, jnp.float32))
 
@@ -190,8 +191,9 @@ class TrafficLedger:
         ``enabled`` at trace time and zeroes every participant but 0, so
         the accumulated total is exact cluster-wide rounds."""
         def _cb(r, verb=verb):
-            e = self.round_counts.setdefault(verb, {"rounds": 0.0})
-            e["rounds"] += float(r)
+            with self._lock:
+                e = self.round_counts.setdefault(verb, {"rounds": 0.0})
+                e["rounds"] += float(r)
 
         jax.debug.callback(_cb, jnp.asarray(rounds, jnp.float32))
 
@@ -203,9 +205,11 @@ class TrafficLedger:
         emits and the row bytes it serves/commits, so totals are
         cluster-wide wire bytes counted exactly once."""
         def _cb(b, verb=verb):
-            e = self.dma_counts.setdefault(verb, {"calls": 0, "bytes": 0.0})
-            e["calls"] += 1
-            e["bytes"] += float(b)
+            with self._lock:
+                e = self.dma_counts.setdefault(
+                    verb, {"calls": 0, "bytes": 0.0})
+                e["calls"] += 1
+                e["bytes"] += float(b)
 
         jax.debug.callback(_cb, jnp.asarray(nbytes, jnp.float32))
 
@@ -215,10 +219,11 @@ class TrafficLedger:
         :meth:`record`: callers check ``enabled`` before calling, so
         disabled ledgers never emit callbacks."""
         def _cb(h, lk, name=name):
-            e = self.cache_counts.setdefault(
-                name, {"hits": 0.0, "lookups": 0.0})
-            e["hits"] += float(h)
-            e["lookups"] += float(lk)
+            with self._lock:
+                e = self.cache_counts.setdefault(
+                    name, {"hits": 0.0, "lookups": 0.0})
+                e["hits"] += float(h)
+                e["lookups"] += float(lk)
 
         jax.debug.callback(_cb, jnp.asarray(hits, jnp.float32),
                            jnp.asarray(lookups, jnp.float32))
@@ -230,10 +235,11 @@ class TrafficLedger:
         as :meth:`record`: callers check ``enabled`` before calling, so
         disabled ledgers never emit callbacks."""
         def _cb(f, w, name=name):
-            e = self.fastpath_counts.setdefault(
-                name, {"fast_windows": 0.0, "windows": 0.0})
-            e["fast_windows"] += float(f)
-            e["windows"] += float(w)
+            with self._lock:
+                e = self.fastpath_counts.setdefault(
+                    name, {"fast_windows": 0.0, "windows": 0.0})
+                e["fast_windows"] += float(f)
+                e["windows"] += float(w)
 
         jax.debug.callback(_cb, jnp.asarray(fast, jnp.float32),
                            jnp.asarray(windows, jnp.float32))
@@ -247,8 +253,9 @@ class TrafficLedger:
         :meth:`record`: callers check ``enabled`` before calling, so
         disabled ledgers never emit callbacks."""
         def _cb(n, name=name):
-            self.corrupt_counts[name] = \
-                self.corrupt_counts.get(name, 0.0) + float(n)
+            with self._lock:
+                self.corrupt_counts[name] = \
+                    self.corrupt_counts.get(name, 0.0) + float(n)
 
         jax.debug.callback(_cb, jnp.asarray(count, jnp.float32))
 
@@ -258,8 +265,9 @@ class TrafficLedger:
         writer's delayed publish was consumed-but-dropped.  Same
         trace-time gating contract as :meth:`record`."""
         def _cb(n, name=name):
-            self.fenced_counts[name] = \
-                self.fenced_counts.get(name, 0.0) + float(n)
+            with self._lock:
+                self.fenced_counts[name] = \
+                    self.fenced_counts.get(name, 0.0) + float(n)
 
         jax.debug.callback(_cb, jnp.asarray(count, jnp.float32))
 

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..core.ack import AckKey, join
-from ..launch.mesh import compat_shard_map
 from ..optim import compression as C
 
 
@@ -121,7 +120,7 @@ def make_grad_sync_shardmap(mesh, param_specs, *, fence="global",
     in_specs = jax.tree.map(in_spec, param_specs,
                             is_leaf=lambda x: isinstance(x, P))
 
-    @functools.partial(compat_shard_map, mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh, check_vma=False,
                        in_specs=(in_specs,), out_specs=in_specs)
     def sync(grads):
         synced, _err = grad_sync(grads, data_axis="data", pod_axis=pod_axis,

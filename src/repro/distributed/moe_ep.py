@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ArchConfig
-from ..launch.mesh import compat_shard_map
 from ..models import moe as M
 from .sharding import TP, dp_axes
 
@@ -43,7 +42,7 @@ def make_moe_fn(cfg: ArchConfig, mesh):
                    TP if S % mesh.shape[TP] == 0 else None, None)
 
         @functools.partial(
-            compat_shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh, check_vma=False,
             in_specs=(param_specs(params), x_spec),
             out_specs=(x_spec, P()))
         def run(p, xl):

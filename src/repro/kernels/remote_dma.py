@@ -13,12 +13,19 @@ drift.
 Dispatch follows :mod:`repro.kernels.ops`: Pallas on TPU, interpret mode
 on CPU (the validation substrate — the kernel body runs with identical
 semantics), ``force_ref=True`` routes to the pure-jnp oracle used by the
-A/B tests.  On the emulation substrate the *wire hop* between the
-requester-side and home-side kernels stays an XLA collective
-(all-gather of descriptors, psum_scatter of served rows) exactly as in
-:func:`repro.core.colls._serve_scatter`; on TPU hardware the same
-descriptor stream feeds :func:`remote_copy_tpu`, a
-``pltpu.make_async_remote_copy`` send/wait pair.
+A/B tests.  The *wire hop* between the requester-side and home-side
+kernels is an XLA collective on every substrate (all-gather of
+descriptors, psum_scatter of served rows), exactly as in
+:func:`repro.core.colls._serve_scatter`; the kernels are the two ends of
+that hop.
+
+On the chip the home table stays in HBM and never enters VMEM whole:
+the row kernels move only the row tiles that hold described rows (see
+"row tiles" below), the scatter updates the table in place, indices and
+masks ride in SMEM as scalar prefetch, and the byte counters are SMEM
+scalars.  Under ``vmap`` (the single-device participant binding) Pallas
+runs a scalar-prefetch kernel once per participant, so each call still
+sees one participant's table.
 
 All kernels take 2-D ``(rows, width)`` buffers — callers flatten item
 dims — and are dtype-generic.  Descriptor layout (8 × int32 =
@@ -40,6 +47,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 #: int32 words per transfer descriptor.
 DESC_WORDS = 8
@@ -59,23 +67,22 @@ def _interpret() -> bool:
 # descriptor build (requester side)
 # ---------------------------------------------------------------------------
 
-def _build_desc_kernel(tgt_ref, idx_ref, en_ref, wire_ref, out_ref, nb_ref,
-                       *, op, row_nbytes):
-    out_ref[...] = jnp.zeros_like(out_ref)
-    nb_ref[0] = 0
-
-    def body(i, _):
-        out_ref[i, 0] = jnp.int32(op)
-        out_ref[i, 1] = tgt_ref[i]
-        out_ref[i, 2] = idx_ref[i]
-        out_ref[i, 3] = (en_ref[i] != 0).astype(jnp.int32)
-        out_ref[i, 4] = jnp.int32(row_nbytes)
-        out_ref[i, 5] = jnp.int32(i)
-        nb_ref[0] += jnp.where(wire_ref[i] != 0, jnp.int32(DESC_BYTES),
-                               jnp.int32(0))
-        return 0
-
-    jax.lax.fori_loop(0, out_ref.shape[0], body, 0)
+def _build_desc_kernel(req_ref, out_ref, nb_ref, *, op, row_nbytes):
+    # req columns: target | index | enabled | wire — one lane per row, so
+    # every descriptor word is a lane-broadcast select, no scalar stores
+    req = req_ref[...]
+    shape = out_ref.shape
+    word = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    seq = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    desc = jnp.where(word == 0, jnp.int32(op), jnp.int32(0))
+    desc = jnp.where(word == 1, req[:, 0:1], desc)
+    desc = jnp.where(word == 2, req[:, 1:2], desc)
+    desc = jnp.where(word == 3, (req[:, 2:3] != 0).astype(jnp.int32), desc)
+    desc = jnp.where(word == 4, jnp.int32(row_nbytes), desc)
+    desc = jnp.where(word == 5, seq, desc)
+    out_ref[...] = desc
+    nb_ref[0, 0] = jnp.sum((req[:, 3:4] != 0).astype(jnp.int32)) \
+        * jnp.int32(DESC_BYTES)
 
 
 def _build_desc_ref(targets, indices, en, wire, op, row_nbytes):
@@ -112,31 +119,78 @@ def build_descriptors(targets, indices, en, *, wire=None, op=OP_READ,
     desc, nb = pl.pallas_call(
         kern,
         out_shape=(jax.ShapeDtypeStruct((R, DESC_WORDS), jnp.int32),
-                   jax.ShapeDtypeStruct((1,), jnp.int32)),
+                   jax.ShapeDtypeStruct((1, 1), jnp.int32)),
+        out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM),
+                   pl.BlockSpec(memory_space=pltpu.SMEM)),
         interpret=_interpret(),
-    )(targets, indices, en, wire)
-    return desc, nb[0]
+    )(jnp.stack([targets, indices, en, wire], axis=1))
+    return desc, nb[0, 0]
+
+
+# ---------------------------------------------------------------------------
+# row tiles
+# ---------------------------------------------------------------------------
+#
+# A 2-D table in HBM is laid out in (8, 128) tiles of 32-bit words, and
+# Mosaic slices such a table only in whole tiles — a kvstore row of
+# ``value_width + 3`` words is not even a whole lane tile.  So the row
+# kernels run a grid over lanes and let the Pallas pipeline move one
+# (T, width) row tile per lane, chosen by the scalar-prefetched index;
+# inside the tile the kernel picks or patches the described row with a
+# sublane select.  Rows travel as same-width signed integers, which keeps
+# the select exact for every dtype (float bit patterns included).
+
+def _tile_rows(dtype) -> int:
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def _as_int_rows(buf2d, T):
+    """Bit-identical integer view of ``buf2d``, padded with zero rows to a
+    whole number of T-row tiles (a no-op for aligned tables)."""
+    bits = jax.lax.bitcast_convert_type(
+        buf2d, jnp.dtype(f"int{8 * buf2d.dtype.itemsize}"))
+    return jnp.pad(bits, ((0, -buf2d.shape[0] % T), (0, 0)))
+
+
+def _from_int_rows(bits, n_rows, dtype):
+    return jax.lax.bitcast_convert_type(bits[:n_rows], dtype)
+
+
+def _pick_row(tile, sub):
+    """Row ``sub`` of a (T, width) integer tile as a (1, width) value:
+    exactly one term of the sum is non-zero, so it is bitwise the row."""
+    row_id = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+    return jnp.sum(jnp.where(row_id == sub, tile, jnp.zeros_like(tile)),
+                   axis=0, keepdims=True, dtype=tile.dtype)
+
+
+#: lanes are served in grid order, one step after another
+_SEQUENTIAL = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
 
 # ---------------------------------------------------------------------------
 # row serve (home side, reads)
 # ---------------------------------------------------------------------------
 
-def _gather_kernel(idx_ref, mask_ref, buf_ref, out_ref, nb_ref, *,
+def _gather_kernel(idx_ref, mask_ref, tile_ref, out_ref, nb_ref, *,
                    row_nbytes):
-    out_ref[...] = jnp.zeros_like(out_ref)
-    nb_ref[0] = 0
+    i = pl.program_id(0)
+    T = out_ref.shape[0]
+    served = mask_ref[i] != 0
 
-    def body(i, _):
-        row = idx_ref[i]
+    @pl.when(i == 0)
+    def _():
+        nb_ref[0, 0] = jnp.int32(0)
 
-        @pl.when(mask_ref[i] != 0)
-        def _():
-            out_ref[i, :] = buf_ref[row, :]
-            nb_ref[0] += jnp.int32(row_nbytes)
-        return 0
+    # lanes i // T * T ... + T - 1 share one output tile
+    @pl.when(i % T == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    jax.lax.fori_loop(0, out_ref.shape[0], body, 0)
+    row = _pick_row(tile_ref[...], idx_ref[i] % T)
+    row_id = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
+    out_ref[...] = jnp.where((row_id == i % T) & served, row, out_ref[...])
+    nb_ref[0, 0] += jnp.where(served, jnp.int32(row_nbytes), jnp.int32(0))
 
 
 def _gather_ref(buf2d, indices, mask, row_nbytes):
@@ -157,44 +211,68 @@ def gather_rows(buf2d, indices, mask, *, force_ref=False):
     row_nbytes = int(buf2d.shape[1]) * buf2d.dtype.itemsize
     if force_ref:
         return _gather_ref(buf2d, indices, mask, row_nbytes)
-    N = indices.shape[0]
+    N, W = indices.shape[0], buf2d.shape[1]
+    T = _tile_rows(buf2d.dtype)
+    table = _as_int_rows(buf2d, T)
+    n_pad = N + -N % T
     kern = functools.partial(_gather_kernel, row_nbytes=row_nbytes)
     rows, nb = pl.pallas_call(
         kern,
-        out_shape=(jax.ShapeDtypeStruct((N, buf2d.shape[1]), buf2d.dtype),
-                   jax.ShapeDtypeStruct((1,), jnp.int32)),
+        out_shape=(jax.ShapeDtypeStruct((n_pad, W), table.dtype),
+                   jax.ShapeDtypeStruct((1, 1), jnp.int32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(N,),
+            in_specs=[pl.BlockSpec((T, W),
+                                   lambda i, idx, msk: (idx[i] // T, 0))],
+            out_specs=(pl.BlockSpec((T, W), lambda i, idx, msk: (i // T, 0)),
+                       pl.BlockSpec((1, 1), lambda i, idx, msk: (0, 0),
+                                    memory_space=pltpu.SMEM))),
+        compiler_params=_SEQUENTIAL,
         interpret=_interpret(),
-    )(indices, mask, buf2d)
-    return rows, nb[0]
+    )(indices, mask, table)
+    return _from_int_rows(rows, N, buf2d.dtype), nb[0, 0]
 
 
 # ---------------------------------------------------------------------------
 # row commit (home side, writes)
 # ---------------------------------------------------------------------------
 
-def _scatter_kernel(idx_ref, apply_ref, wire_ref, val_ref, buf_ref,
-                    out_ref, nb_ref, *, row_nbytes):
-    out_ref[...] = buf_ref[...]
-    nb_ref[0] = 0
+def _scatter_kernel(lane_ref, tile_of_ref, sub_ref, apply_ref, wire_ref,
+                    val_ref, tile_ref, out_ref, nb_ref, *, row_nbytes):
+    # step j serves lane lane_ref[j]; steps are ordered by home tile and,
+    # within a tile, by lane — so each tile is fetched once, patched by
+    # its lanes in lane order (a later lane to the same row wins), and
+    # written back once.
+    j = pl.program_id(0)
+    T = out_ref.shape[0]
+    lane = lane_ref[j]
 
-    def body(i, _):
-        row = idx_ref[i]
+    @pl.when(j == 0)
+    def _():
+        nb_ref[0, 0] = jnp.int32(0)
 
-        @pl.when(apply_ref[i] != 0)
-        def _():
-            out_ref[row, :] = val_ref[i, :]
-        nb_ref[0] += jnp.where(wire_ref[i] != 0, jnp.int32(row_nbytes),
-                               jnp.int32(0))
-        return 0
+    @pl.when((j == 0) | (tile_of_ref[jnp.maximum(j - 1, 0)]
+                         != tile_of_ref[j]))
+    def _():
+        out_ref[...] = tile_ref[...]
 
-    jax.lax.fori_loop(0, idx_ref.shape[0], body, 0)
+    @pl.when(apply_ref[lane] != 0)
+    def _():
+        row_id = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 0)
+        out_ref[...] = jnp.where(row_id == sub_ref[j],
+                                 _pick_row(val_ref[...], lane % T),
+                                 out_ref[...])
+
+    nb_ref[0, 0] += jnp.where(wire_ref[lane] != 0, jnp.int32(row_nbytes),
+                              jnp.int32(0))
 
 
 def _scatter_ref(buf2d, indices, values, apply_mask, wire_mask, row_nbytes):
     n = indices.shape[0]
     # sequential in-order application == last-writer-wins, computed as a
     # winner mask so one scatter commits the surviving rows (the oracle
-    # mirror of the kernel's fori_loop ordering).
+    # mirror of the kernel's lane-order application).
     win = apply_mask != 0
     order = jnp.arange(n)
     later_same = (indices[None, :] == indices[:, None]) & win[None, :] \
@@ -209,12 +287,13 @@ def _scatter_ref(buf2d, indices, values, apply_mask, wire_mask, row_nbytes):
 def scatter_rows(buf2d, indices, values, apply_mask, wire_mask, *,
                  force_ref=False):
     """Commit N described rows into the home buffer **in lane order** —
-    the kernel's sequential loop realizes last-writer-wins natively, so
-    racy lanes need no winner-mask precomputation.  Lane i stores
-    ``values[i]`` at ``indices[i]`` iff ``apply_mask[i]``; measured
-    payload bytes count ``wire_mask`` lanes (the caller excludes
+    the kernel applies lanes sequentially, realizing last-writer-wins
+    natively, so racy lanes need no winner-mask precomputation.  Lane i
+    stores ``values[i]`` at ``indices[i]`` iff ``apply_mask[i]``;
+    measured payload bytes count ``wire_mask`` lanes (the caller excludes
     self-origin lanes — a local store moves no wire bytes but still
-    commits).  Returns (new_buf2d, measured_bytes)."""
+    commits).  ``indices`` must be pre-clipped to range.  The table is
+    updated in place.  Returns (new_buf2d, measured_bytes)."""
     indices = indices.astype(jnp.int32)
     apply_mask = jnp.asarray(apply_mask).astype(jnp.int32)
     wire_mask = jnp.asarray(wire_mask).astype(jnp.int32)
@@ -222,49 +301,32 @@ def scatter_rows(buf2d, indices, values, apply_mask, wire_mask, *,
     if force_ref:
         return _scatter_ref(buf2d, indices, values, apply_mask, wire_mask,
                             row_nbytes)
+    n, W = indices.shape[0], buf2d.shape[1]
+    T = _tile_rows(buf2d.dtype)
+    table = _as_int_rows(buf2d, T)
+    vals = _as_int_rows(values.astype(buf2d.dtype), T)
+    # visit lanes grouped by home tile, keeping lane order inside a tile
+    lane = jnp.argsort(indices // T, stable=True).astype(jnp.int32)
+    tile_of = indices[lane] // T
+    sub = indices[lane] % T
     kern = functools.partial(_scatter_kernel, row_nbytes=row_nbytes)
     out, nb = pl.pallas_call(
         kern,
-        out_shape=(jax.ShapeDtypeStruct(buf2d.shape, buf2d.dtype),
-                   jax.ShapeDtypeStruct((1,), jnp.int32)),
+        out_shape=(jax.ShapeDtypeStruct(table.shape, table.dtype),
+                   jax.ShapeDtypeStruct((1, 1), jnp.int32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(n,),
+            in_specs=[pl.BlockSpec((T, W),
+                                   lambda j, ln, tl, *_: (ln[j] // T, 0)),
+                      pl.BlockSpec((T, W),
+                                   lambda j, ln, tl, *_: (tl[j], 0))],
+            out_specs=(pl.BlockSpec((T, W),
+                                    lambda j, ln, tl, *_: (tl[j], 0)),
+                       pl.BlockSpec((1, 1), lambda j, *_: (0, 0),
+                                    memory_space=pltpu.SMEM))),
+        input_output_aliases={6: 0},
+        compiler_params=_SEQUENTIAL,
         interpret=_interpret(),
-    )(indices, apply_mask, wire_mask, values, buf2d)
-    return out, nb[0]
-
-
-# ---------------------------------------------------------------------------
-# hardware wire hop (TPU only)
-# ---------------------------------------------------------------------------
-
-def remote_copy_tpu(src, *, device_id, axis: str):
-    """One async remote copy of ``src`` to the same-named buffer on
-    ``device_id`` — the hardware realization of the descriptor wire hop,
-    a ``pltpu.make_async_remote_copy`` send/wait pair per the Pallas
-    async-copy contract.  Only reachable when the process actually runs
-    on TPU hardware (the interpret substrate has no remote-DMA
-    emulation); the emulation path keeps the XLA collective hop and this
-    kernel is exercised by the hardware suites.
-    """
-    if _interpret():  # pragma: no cover - guard, exercised only off-TPU
-        raise NotImplementedError(
-            "remote_copy_tpu needs TPU hardware; the CPU substrate "
-            "realizes the wire hop with XLA collectives instead")
-    from jax.experimental.pallas import tpu as pltpu  # pragma: no cover
-
-    def kern(src_ref, dst_ref, send_sem, recv_sem):  # pragma: no cover
-        copy = pltpu.make_async_remote_copy(
-            src_ref=src_ref, dst_ref=dst_ref,
-            send_sem=send_sem, recv_sem=recv_sem,
-            device_id=(device_id,),
-            device_id_type=pltpu.DeviceIdType.LOGICAL)
-        copy.start()
-        copy.wait()
-
-    return pl.pallas_call(  # pragma: no cover
-        kern,
-        out_shape=jax.ShapeDtypeStruct(src.shape, src.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
-        scratch_shapes=[pltpu.SemaphoreType.DMA, pltpu.SemaphoreType.DMA],
-        compiler_params=pltpu.TPUCompilerParams(has_side_effects=True),
-    )(src)
+    )(lane, tile_of, sub, apply_mask, wire_mask, vals, table)
+    return _from_int_rows(out, buf2d.shape[0], buf2d.dtype), nb[0, 0]

@@ -1,51 +1,19 @@
-"""Production meshes + jax-version mesh compatibility helpers.
+"""Production meshes.
 
 A FUNCTION, not a module-level constant — importing this module never
 touches jax device state (device count is locked at first backend init).
-
-The compat helpers absorb the jax 0.4 → 0.5+ mesh API churn so test and
-launch code runs unmodified on both: ``jax.sharding.AxisType`` (and the
-``axis_types=`` kwarg of ``jax.make_mesh``) only exist on newer jax, and
-``AbstractMesh`` switched from a single ``((name, size), ...)`` tuple to
-``(axis_sizes, axis_names)``.
+Axes are Auto-typed: the sharding rules in :mod:`repro.distributed`
+place arrays through ``NamedSharding``/``shard_map``, not explicit-axis
+types.
 """
 from __future__ import annotations
 
 import jax
 
 
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` with explicit Auto axis types where the installed
-    jax supports them (0.5+), plain otherwise (0.4.x defaults to Auto)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
-
-
-def compat_abstract_mesh(shape, axes):
-    """``jax.sharding.AbstractMesh(shape, axes)`` across the signature
-    change: new jax takes (axis_sizes, axis_names); jax 0.4.x takes one
-    ``((name, size), ...)`` tuple."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-
-
-def compat_shard_map(fn=None, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across the 0.4 → 0.5+ move out of
-    ``jax.experimental`` (and the ``check_rep`` → ``check_vma`` rename).
-    Works as a direct call or via ``functools.partial`` as a decorator,
-    mirroring the ``jax.shard_map`` call shape."""
-    def wrap(f):
-        if hasattr(jax, "shard_map"):                    # jax >= 0.5
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        from jax.experimental.shard_map import shard_map  # jax 0.4.x
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-    return wrap(fn) if fn is not None else wrap
+def _auto_mesh(shape, axes):
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, dp: int = 16,
@@ -60,9 +28,9 @@ def make_production_mesh(*, multi_pod: bool = False, dp: int = 16,
     assert dp * tp == 256, (dp, tp)
     shape = (2, dp, tp) if multi_pod else (dp, tp)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_debug_mesh(n_data: int = 1, n_model: int = 1):
     """Small mesh over however many devices the host exposes."""
-    return compat_make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))

@@ -126,8 +126,7 @@ def moe_block_a2a(params, x, cfg: ArchConfig, axis: str):
     reads back (DESIGN.md §3).
     """
     mo = cfg.moe
-    from ..core.colls import axis_size
-    P = axis_size(axis)
+    P = jax.lax.axis_size(axis)
     E_local = mo.n_experts // P
     B, S, d = x.shape
     T = B * S

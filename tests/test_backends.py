@@ -22,6 +22,7 @@ with no INSERT/MOVE lanes must keep the fast path's round shape — no
 rounds must be observable in the ledger totals.
 """
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -589,6 +590,40 @@ class TestAllocFold:
         rounds = mgr.traffic.rounds_summary()
         assert f"{kv.full_name}.alloc" not in rounds
         assert f"{kv.full_name}.move_read" not in rounds
+
+
+@pytest.mark.parametrize("backend", ALL_BACKENDS)
+def test_ledger_totals_repeat_exactly(backend):
+    """The ledger's callbacks run on several threads at once; the same
+    window must count the same totals every time (an unguarded update
+    would lose counts depending on thread timing)."""
+    n = 8                     # more participants → more concurrent callbacks
+    mgr = make_manager(n, backend=backend)
+    mgr.traffic.enable()
+    kv = KVStore(None, f"bledger_{backend}", mgr, slots_per_node=8,
+                 value_width=2, num_locks=8, index_capacity=128,
+                 placement="hashed")
+    step = jax.jit(lambda s, o, k, v: mgr.runtime.run(kv.op_window, s, o,
+                                                      k, v))
+    keys = np.arange(1, 2 * n + 1).reshape(n, 2)
+    op = jnp.full((n, 2), INSERT, jnp.int32)
+    val = jnp.asarray([[kvmod.v(int(k), 1) for k in lane] for lane in keys],
+                      jnp.int32)
+    prev = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        totals = set()
+        for _ in range(12):
+            mgr.traffic.reset()
+            _st, res = step(kv.init_state(), op, jnp.asarray(keys, jnp.uint32),
+                            val)
+            jax.block_until_ready(res)
+            jax.effects_barrier()
+            totals.add((mgr.traffic.total_rounds(), mgr.traffic.total_bytes(),
+                        mgr.traffic.total_dma_bytes()))
+    finally:
+        sys.setswitchinterval(prev)
+    assert len(totals) == 1, totals
 
 
 # ------------------------------------------------------------ serving engine

@@ -15,7 +15,9 @@ PREFIX = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P, NamedSharding
-    from repro.launch.mesh import compat_make_mesh
+    def auto_mesh(shape, axes):
+        return jax.make_mesh(
+            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 """)
 
 
@@ -33,7 +35,7 @@ def run_prog(body, timeout=600):
 def test_grad_sync_hierarchical_and_fence_equivalence():
     run_prog("""
         from repro.distributed.collectives import make_grad_sync_shardmap
-        mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
         grads = {"a": jnp.arange(32.0).reshape(8, 4),
                  "b": {"c": jnp.ones((4, 8)) * 3}}
         specs = {"a": P(None, "model"), "b": {"c": P("model", None)}}
@@ -55,7 +57,7 @@ def test_grad_sync_hierarchical_and_fence_equivalence():
 def test_grad_sync_int8_compression_close():
     run_prog("""
         from repro.distributed.collectives import make_grad_sync_shardmap
-        mesh = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = auto_mesh((2, 2, 2), ("pod", "data", "model"))
         rng = np.random.default_rng(0)
         g = jnp.asarray(rng.standard_normal((16, 16)), jnp.float32)
         grads = {"w": g}
@@ -81,7 +83,7 @@ def test_moe_a2a_matches_local():
         # capacity high enough that nothing drops → paths agree exactly
         cfg = cfg.replace(moe=dataclasses.replace(
             cfg.moe, n_experts=8, capacity_factor=float(8)))
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        mesh = auto_mesh((2, 4), ("data", "model"))
         key = jax.random.PRNGKey(0)
         params = M.init_moe(key, cfg)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 8, cfg.d_model),

@@ -263,12 +263,3 @@ class TestRemoteDma:
         np.testing.assert_array_equal(np.asarray(rows), exp)
         np.testing.assert_array_equal(
             np.asarray(nb), np.asarray(mask).sum(axis=1) * W * 4)
-
-    def test_remote_copy_tpu_guarded_off_hardware(self):
-        """The hardware wire-hop kernel refuses to run on the interpret
-        substrate (no remote-DMA emulation) instead of miscompiling."""
-        if jax.default_backend() == "tpu":
-            pytest.skip("hardware path exercised by TPU suites")
-        with pytest.raises(NotImplementedError, match="TPU hardware"):
-            rdma.remote_copy_tpu(jnp.zeros((4, 4), jnp.float32),
-                                 device_id=1, axis="nodes")

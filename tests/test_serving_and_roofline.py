@@ -82,7 +82,7 @@ ENTRY %main () -> f32[] {
         marker words outside custom-call lines."""
         hlo = """
 ENTRY %main () -> f32[] {
-  %send = f32[4,128]{1,0} custom-call(f32[4,128]{1,0} %src), custom_call_target="tpu_custom_call", metadata={op_name="pallas_call[name=remote_copy_tpu]"}
+  %send = f32[4,128]{1,0} custom-call(f32[4,128]{1,0} %src), custom_call_target="tpu_custom_call", metadata={op_name="pallas_call[name=remote_copy]"}
   %tup = (f32[2,2]{1,0}, s32[8]{0}) custom-call-start(%a), backend_config="async_remote_copy"
   %plain = f32[64]{0} custom-call(f32[64]{0} %b), custom_call_target="Sharding"
   %fus = f32[64]{0} fusion(f32[64]{0} %c), calls=%remote_dma_helper

@@ -16,11 +16,8 @@ PROG = textwrap.dedent("""
                             INSERT, GET, NOP)
 
     P = 8
-    if hasattr(jax.sharding, "AxisType"):          # jax >= 0.5
-        mesh = jax.make_mesh((P,), ("nodes",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    else:
-        mesh = jax.make_mesh((P,), ("nodes",))
+    mesh = jax.make_mesh((P,), ("nodes",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     mgr = make_manager(P, axis="nodes", mesh=mesh)
 
     # --- barrier under shard_map
@@ -73,11 +70,8 @@ PROG2 = textwrap.dedent("""
     from repro.core.kvstore import IDX_NODE, IDX_STATE, _USED
 
     P, B, W = 8, 2, 2
-    if hasattr(jax.sharding, "AxisType"):          # jax >= 0.5
-        mesh = jax.make_mesh((P,), ("nodes",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    else:
-        mesh = jax.make_mesh((P,), ("nodes",))
+    mesh = jax.make_mesh((P,), ("nodes",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     mgr = make_manager(P, axis="nodes", mesh=mesh)
 
     kv = KVStore(None, "kv", mgr, slots_per_node=4, value_width=W,

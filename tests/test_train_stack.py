@@ -17,8 +17,8 @@ from repro.train import make_train_step
 
 
 def tiny_mesh():
-    from repro.launch.mesh import compat_make_mesh
-    return compat_make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_debug_mesh
+    return make_debug_mesh(1, 1)
 
 
 def setup(arch="llama3.2-3b", **tkw):
@@ -89,8 +89,7 @@ class TestShardingRules:
         import re
 
         from repro.distributed.sharding import PARAM_RULES, _resolve_template
-        from repro.launch.mesh import compat_abstract_mesh
-        mesh = compat_abstract_mesh((1, 4), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((1, 4), ("data", "model"))
         # wq (d=64, H*hd=64): shardable over 4
         for pat, template in PARAM_RULES:
             if re.search(pat, "stack/super/0/attn/wq"):
@@ -107,8 +106,7 @@ class TestShardingRules:
     def test_zero_specs_shard_moments_over_data(self):
         cfg = get_smoke_config("qwen3-8b").replace(dtype="float32")
         from repro.models import build_model
-        from repro.launch.mesh import compat_abstract_mesh
-        mesh = compat_abstract_mesh((4, 1), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((4, 1), ("data", "model"))
         model = build_model(cfg)
         params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
         opt = make_optimizer(TrainConfig(zero_stage=2))

@@ -24,10 +24,23 @@ shard_map) with collectives over ``axis``.
 """
 from __future__ import annotations
 
+import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+
+
+def verb(fn):
+    """Name the ops of a verb that moves data between participants
+    ``verb.<function name>`` in the compiled program's metadata, so a
+    profiler trace can give the verb its device time.  A name scope is
+    metadata only: the ops and their order are unchanged."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(f"verb.{fn.__name__}"):
+            return fn(*args, **kwargs)
+    return scoped
 
 
 def my_id(axis: str):
@@ -88,6 +101,7 @@ def record_fastpath(ledger, name, fast, windows):
         ledger.record_fastpath(name, fast, windows)
 
 
+@verb
 def bcast_from(value, owner, axis: str):
     """Broadcast ``value`` from participant ``owner`` to all participants.
 
@@ -102,6 +116,7 @@ def bcast_from(value, owner, axis: str):
     return jax.tree.map(lambda v: jax.lax.psum(v, axis), masked)
 
 
+@verb
 def gather_rows(value, axis: str):
     """All-gather each participant's ``value`` into a leading-P table.
 
@@ -111,6 +126,7 @@ def gather_rows(value, axis: str):
     return jax.lax.all_gather(value, axis, axis=0, tiled=False)
 
 
+@verb
 def prefix_sums(x, axis: str) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """(exclusive_prefix_at_me, total, gathered) for scalar ``x`` per node.
 
@@ -126,6 +142,7 @@ def prefix_sums(x, axis: str) -> Tuple[jax.Array, jax.Array, jax.Array]:
     return excl, total, g
 
 
+@verb
 def window_prefix(x, axis: str) -> Tuple[jax.Array, jax.Array]:
     """(exclusive_prefix, total) for a (B,) lane vector per participant,
     flattened in **(participant, lane) lexicographic order** over all P·B

@@ -888,13 +888,14 @@ class KVStore(Channel):
             # read-tier coherence on the scalar spec path too (§8.3)
             rec = jnp.concatenate(
                 [rec, (do_upd | do_del).astype(jnp.int32).reshape(1)])
-        recs = jax.lax.all_gather(rec, self.axis, axis=0)        # (P, 5|6)
-        if self.cache is not None:
-            st = st._replace(cache=self.cache.invalidate(
-                st.cache, recs[:, 2], recs[:, 3], recs[:, 5] != 0))
-            recs = recs[:, :5]
-        n_recs = jnp.sum(recs[:, 0] != 0).astype(jnp.uint32)
-        st, applied = self._apply_tracker(st, recs)
+        with jax.named_scope("kv.tracker"):
+            recs = jax.lax.all_gather(rec, self.axis, axis=0)        # (P, 5|6)
+            if self.cache is not None:
+                st = st._replace(cache=self.cache.invalidate(
+                    st.cache, recs[:, 2], recs[:, 3], recs[:, 5] != 0))
+                recs = recs[:, :5]
+            n_recs = jnp.sum(recs[:, 0] != 0).astype(jnp.uint32)
+            st, applied = self._apply_tracker(st, recs)
         # acknowledge through the SST; inserter requires all peers caught up.
         acks, _a = self.acks.push_accumulate(st.acks, n_recs)
         my_acked = self.acks.rows(acks)[me]
@@ -1195,14 +1196,15 @@ class KVStore(Channel):
             # protocol validates.)
             rec = jnp.concatenate(
                 [rec, (do_upd | do_del).astype(jnp.int32)[:, None]], axis=1)
-        recs = jax.lax.all_gather(rec, self.axis, axis=0)      # (P, B, 5|6)
-        recs = recs.reshape(-1, rec.shape[1])                  # participant-major
-        if self.cache is not None:
-            st = st._replace(cache=self.cache.invalidate(
-                st.cache, recs[:, 2], recs[:, 3], recs[:, 5] != 0))
-            recs = recs[:, :5]
-        n_recs = jnp.sum(recs[:, 0] != 0).astype(jnp.uint32)
-        st, applied = self._apply_tracker(st, recs)
+        with jax.named_scope("kv.tracker"):
+            recs = jax.lax.all_gather(rec, self.axis, axis=0)  # (P, B, 5|6)
+            recs = recs.reshape(-1, rec.shape[1])          # participant-major
+            if self.cache is not None:
+                st = st._replace(cache=self.cache.invalidate(
+                    st.cache, recs[:, 2], recs[:, 3], recs[:, 5] != 0))
+                recs = recs[:, :5]
+            n_recs = jnp.sum(recs[:, 0] != 0).astype(jnp.uint32)
+            st, applied = self._apply_tracker(st, recs)
         my_applied = jax.lax.dynamic_slice(applied, (me * B,), (B,))
         # acknowledge all applied records through the SST in one push;
         # inserters require every peer caught up before setting valid.
@@ -1437,14 +1439,15 @@ class KVStore(Channel):
                 [rec,
                  (do_upd | do_del | do_move).astype(jnp.int32)[:, None],
                  node[:, None], slot[:, None]], axis=1)
-        recs = jax.lax.all_gather(rec, self.axis, axis=0)
-        recs = recs.reshape(-1, rec.shape[1])               # participant-major
-        if self.cache is not None:
-            st = st._replace(cache=self.cache.invalidate(
-                st.cache, recs[:, 6], recs[:, 7], recs[:, 5] != 0))
-            recs = recs[:, :5]
-        n_recs = jnp.sum(recs[:, 0] != 0).astype(jnp.uint32)
-        st, applied = self._apply_tracker(st, recs)
+        with jax.named_scope("kv.tracker"):
+            recs = jax.lax.all_gather(rec, self.axis, axis=0)
+            recs = recs.reshape(-1, rec.shape[1])           # participant-major
+            if self.cache is not None:
+                st = st._replace(cache=self.cache.invalidate(
+                    st.cache, recs[:, 6], recs[:, 7], recs[:, 5] != 0))
+                recs = recs[:, :5]
+            n_recs = jnp.sum(recs[:, 0] != 0).astype(jnp.uint32)
+            st, applied = self._apply_tracker(st, recs)
         my_applied = jax.lax.dynamic_slice(applied, (me * B,), (B,))
         acks, _a = self.acks.push_accumulate(st.acks, n_recs)
         my_acked = self.acks.rows(acks)[me]
@@ -1596,14 +1599,19 @@ class KVStore(Channel):
         # one (B, C) index probe for the whole window; the service loop
         # keeps the per-lane view current incrementally (tracker records
         # are the only writers of the index).
-        found0, _pos, node0, slot0, ctr0 = jax.vmap(
-            lambda k: self._index_lookup(st, k))(keys)
+        with jax.named_scope("kv.probe"):
+            found0, _pos, node0, slot0, ctr0 = jax.vmap(
+                lambda k: self._index_lookup(st, k))(keys)
         look0 = (found0, node0, slot0, ctr0)
 
+        # each phase below runs under one ``kv.<phase>`` name scope, so a
+        # profiler trace gives every phase its device time; the top-level
+        # scopes do not nest, and ``kv.tracker`` nests in ``kv.service``
         if not lockfree:
             plan = None
-            lstate, ticket = self.locks.acquire_window(st.locks, lock_id,
-                                                       want_lock)
+            with jax.named_scope("kv.lock_acquire"):
+                lstate, ticket = self.locks.acquire_window(
+                    st.locks, lock_id, want_lock)
         else:
             # §11: the plan's single gather subsumes the acquire gather
             # (fused-FAA ranks/totals → bit-identical tickets + counters)
@@ -1614,33 +1622,35 @@ class KVStore(Channel):
             # outright — the skipped plan's outputs are exactly the
             # defaults the carry holds (zero ranks/totals move no ticket
             # counter, nothing to invalidate, vacuously fast).
-            any_want = jax.lax.psum(
-                jnp.any(want_lock).astype(jnp.int32), self.axis) > 0
-            N = self.P * B
+            with jax.named_scope("kv.plan"):
+                any_want = jax.lax.psum(
+                    jnp.any(want_lock).astype(jnp.int32), self.axis) > 0
+                N = self.P * B
 
-            def pbody(c):
-                p = self._window_plan(ops, keys, lock_id, want_lock, look0)
-                return (jnp.zeros((), jnp.bool_), p["rank"], p["totals"],
-                        p["round_no"], p["write_winner"], p["win_fast"],
-                        p["any_alloc"],
-                        p["inv_node"], p["inv_slot"], p["inv_flag"])
+                def pbody(c):
+                    p = self._window_plan(ops, keys, lock_id, want_lock,
+                                          look0)
+                    return (jnp.zeros((), jnp.bool_), p["rank"], p["totals"],
+                            p["round_no"], p["write_winner"], p["win_fast"],
+                            p["any_alloc"],
+                            p["inv_node"], p["inv_slot"], p["inv_flag"])
 
-            (_t, rank, totals, rno, wwin, wfast, aalloc, inode, islot,
-             iflag) = jax.lax.while_loop(
-                    lambda c: c[0], pbody,
-                    (any_want, jnp.zeros((B,), jnp.uint32),
-                     jnp.zeros((self.L,), jnp.uint32),
-                     jnp.zeros((B,), jnp.int32),
-                     jnp.zeros((B,), jnp.bool_),
-                     jnp.ones((), jnp.bool_),
-                     jnp.zeros((), jnp.bool_),
-                     jnp.zeros((N,), jnp.int32),
-                     jnp.zeros((N,), jnp.int32),
-                     jnp.zeros((N,), jnp.bool_)))
-            plan = dict(rank=rank, totals=totals, round_no=rno,
-                        write_winner=wwin, win_fast=wfast,
-                        any_want=any_want, any_alloc=aalloc,
-                        inv_node=inode, inv_slot=islot, inv_flag=iflag)
+                (_t, rank, totals, rno, wwin, wfast, aalloc, inode, islot,
+                 iflag) = jax.lax.while_loop(
+                        lambda c: c[0], pbody,
+                        (any_want, jnp.zeros((B,), jnp.uint32),
+                         jnp.zeros((self.L,), jnp.uint32),
+                         jnp.zeros((B,), jnp.int32),
+                         jnp.zeros((B,), jnp.bool_),
+                         jnp.ones((), jnp.bool_),
+                         jnp.zeros((), jnp.bool_),
+                         jnp.zeros((N,), jnp.int32),
+                         jnp.zeros((N,), jnp.int32),
+                         jnp.zeros((N,), jnp.bool_)))
+                plan = dict(rank=rank, totals=totals, round_no=rno,
+                            write_winner=wwin, win_fast=wfast,
+                            any_want=any_want, any_alloc=aalloc,
+                            inv_node=inode, inv_slot=islot, inv_flag=iflag)
         if not lockfree:
             # every acquired ticket completes within this window, so the
             # deferred end-of-window release bumps now_serving by exactly
@@ -1654,8 +1664,9 @@ class KVStore(Channel):
         # line they touch (§8.3 refill-then-invalidate order).  GETs never
         # read lock state, so the lock-free dispatch is free to defer its
         # counter bumps into the gated mutation half below.
-        get_val, get_found, retries, st = self._get_window(
-            st, keys, ops == GET, look=look0)
+        with jax.named_scope("kv.get"):
+            get_val, get_found, retries, st = self._get_window(
+                st, keys, ops == GET, look=look0)
 
         if self.reference_impl:
             round_no, write_winner, any_alloc = None, None, None
@@ -1663,8 +1674,9 @@ class KVStore(Channel):
             # work-proportional schedule, computed once outside the loop
             # (the placed path's allocation request rides this gather as
             # the uniform ``any_alloc`` flag, §14)
-            round_no, write_winner, any_alloc = self._service_schedule(
-                ops, keys, lock_id, ticket, want_lock)
+            with jax.named_scope("kv.schedule"):
+                round_no, write_winner, any_alloc = self._service_schedule(
+                    ops, keys, lock_id, ticket, want_lock)
 
         def _serve_rounds(st_s, pending0, succ0, ticket, round_no,
                           write_winner, any_alloc):
@@ -1707,9 +1719,10 @@ class KVStore(Channel):
             # zero).
             def mut_body(c):
                 _todo, locks, cache, rows, _ticket, _tot = c
-                lstate, ticket = self.locks.acquire_window_prepared(
-                    locks, lock_id, want_lock, plan["rank"],
-                    plan["totals"])
+                with jax.named_scope("kv.lock_acquire"):
+                    lstate, ticket = self.locks.acquire_window_prepared(
+                        locks, lock_id, want_lock, plan["rank"],
+                        plan["totals"])
                 lock_totals = lstate.next_ticket - locks.next_ticket
                 # §8.3 coherence for fast windows: the locked rounds
                 # piggyback the "row mutated" flag on their tracker
@@ -1744,8 +1757,9 @@ class KVStore(Channel):
                         assume_unique=True)
                     return jnp.zeros((), jnp.bool_), rows2
 
-                _ft, rows = jax.lax.while_loop(
-                    lambda fc: fc[0], fbody, (win_fast, rows))
+                with jax.named_scope("kv.service"):
+                    _ft, rows = jax.lax.while_loop(
+                        lambda fc: fc[0], fbody, (win_fast, rows))
                 return (jnp.zeros((), jnp.bool_), lstate, cache, rows,
                         ticket, lock_totals)
 
@@ -1771,17 +1785,20 @@ class KVStore(Channel):
             pending0 = want_lock
             succ0 = jnp.zeros((B,), jnp.bool_)
 
-        st, _pending, succ, _look, _r = _serve_rounds(
-            st, pending0, succ0, ticket, round_no, write_winner, any_alloc)
+        with jax.named_scope("kv.service"):
+            st, _pending, succ, _look, _r = _serve_rounds(
+                st, pending0, succ0, ticket, round_no, write_winner,
+                any_alloc)
 
         if not self.reference_impl:
             # deferred batched release: critical-section effects joined
             # first (one end-of-window release fence, §5.4), then every
             # lock's now_serving advances by its completed-ticket count
-            gate = join(AckKey([st.rows.buf]), True)
-            ns = jnp.where(gate, st.locks.now_serving + lock_totals,
-                           st.locks.now_serving)
-            st = st._replace(locks=st.locks._replace(now_serving=ns))
+            with jax.named_scope("kv.release"):
+                gate = join(AckKey([st.rows.buf]), True)
+                ns = jnp.where(gate, st.locks.now_serving + lock_totals,
+                               st.locks.now_serving)
+                st = st._replace(locks=st.locks._replace(now_serving=ns))
 
         is_get = ops == GET
         return st, KVResult(

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from .ack import ALL_PEERS, AckKey, make_ack
 from .backends import get_backend
+from .colls import verb
 from .channel import Channel
 from .runtime import Manager
 
@@ -74,6 +75,7 @@ class SharedRegion(Channel):
         return state._replace(buf=state.buf.at[row].set(values, mode="drop"))
 
     # -- one-sided access (collectively served; see colls.py) -------------------
+    @verb
     def read(self, state: SharedRegionState, target, index, pred=True):
         """One-sided read of row ``index`` at participant ``target``."""
         val = self.backend.read(state.buf, target, index, self.axis,
@@ -82,6 +84,7 @@ class SharedRegion(Channel):
         ack = make_ack(val, "read", self.full_name, ALL_PEERS, self.item_nbytes)
         return val, self.mgr.track(ack)
 
+    @verb
     def read_batch(self, state: SharedRegionState, targets, indices,
                    preds=None, coalesce=True):
         """Batched one-sided read; ``coalesce`` (default on) dedupes each
@@ -95,6 +98,7 @@ class SharedRegion(Channel):
                        self.item_nbytes * int(targets.shape[0]))
         return vals, self.mgr.track(ack)
 
+    @verb
     def write(self, state: SharedRegionState, target, index, value,
               pred=True):
         """One-sided write of ``value`` to row ``index`` at ``target``."""
@@ -105,6 +109,7 @@ class SharedRegion(Channel):
         ack = make_ack(buf, "write", self.full_name, ALL_PEERS, self.item_nbytes)
         return new, self.mgr.track(ack)
 
+    @verb
     def write_batch(self, state: SharedRegionState, targets, indices, values,
                     preds=None, assume_unique=False):
         buf = self.backend.write_batch(state.buf, targets, indices, values,

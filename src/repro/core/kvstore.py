@@ -319,19 +319,15 @@ class KVStore(Channel):
     def _probe(self, idx, key):
         """One bounded linear-probe pass for ``key`` over the (C, 5) index.
 
-        Returns ``(has_match, match_pos, has_free, free_pos)`` over the
-        PROBE-position window starting at ``hash(key) % C``:
+        Returns ``(has_match, match_pos, entry)`` over the PROBE-position
+        window starting at ``hash(key) % C``, where ``entry`` is the
+        (5,) index entry at ``match_pos``, read from the gathered window.
+        A *match* is a USED position holding ``key`` with no EMPTY
+        position before it in the window (an EMPTY terminates the chain —
+        tombstones do not, so deletion never hides a later entry).
 
-        * a *match* is a USED position holding ``key`` with no EMPTY
-          position before it in the window (an EMPTY terminates the chain —
-          tombstones do not, so deletion never hides a later entry);
-        * a *free* position is EMPTY or tombstone — the insert target is
-          the first one, which reclaims tombstones and, because inserts
-          always take the first free position, preserves the no-EMPTY-
-          before-an-entry invariant the lookup termination relies on.
-
-        O(PROBE) work in ONE row gather; every caller (lookup, tracker
-        apply) shares this logic so the invariants live in one place.
+        O(PROBE) work in ONE row gather: the matched entry comes out of
+        the window already read, never out of the whole index again.
         """
         key = jnp.asarray(key, jnp.uint32)
         pos_w = self._probe_window(key)
@@ -340,9 +336,8 @@ class KVStore(Channel):
         emp = (states == _EMPTY).astype(jnp.int32)
         before_empty = (jnp.cumsum(emp) - emp) == 0   # strictly before 1st EMPTY
         match = before_empty & (states == _USED) & (w[:, IDX_KEY] == _u2i(key))
-        free = (states == _EMPTY) | (states == _TOMB)
-        return (jnp.any(match), pos_w[jnp.argmax(match)],
-                jnp.any(free), pos_w[jnp.argmax(free)])
+        first = jnp.argmax(match)
+        return jnp.any(match), pos_w[first], w[first]
 
     def _index_lookup(self, st: KVStoreState, key):
         """key → (found, pos, node, slot, ctr); dispatches to the O(PROBE)
@@ -354,9 +349,10 @@ class KVStore(Channel):
         return self._index_lookup_hash(st, key)
 
     def _index_lookup_hash(self, st: KVStoreState, key):
-        found, mpos, _hf, _fp = self._probe(st.idx, key)
+        found, mpos, entry = self._probe(st.idx, key)
         pos = jnp.where(found, mpos, 0)
-        row = st.idx[pos]
+        # a miss reports entry 0, as the flat scan's argmax-of-all-False does
+        row = jnp.where(found, entry, st.idx[0])
         return (found, pos, row[IDX_NODE], row[IDX_SLOT], _i2u(row[IDX_CTR]))
 
     def _index_lookup_reference(self, st: KVStoreState, key):

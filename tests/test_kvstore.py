@@ -744,6 +744,61 @@ class TestHashIndex:
         np.testing.assert_array_equal(applied, [True] * PROBE + [False])
         assert np.asarray(st.idx_overflow).all()
 
+    # C = 16, PROBE = 4: entry 0 holds a key of its own; bucket 5 holds a
+    # full window with a tombstone (k2 deleted), bucket 11 a full window
+    # of USED entries; bucket 2 is EMPTY.
+    _LC, _LPROBE = 16, 4
+
+    @pytest.fixture(scope="class")
+    def tombstoned(self):
+        C = self._LC
+        h = _ApplyHarness(C=C, probe=self._LPROBE)
+        z = _keys_in_bucket(C, 0, 1)[0]
+        k = _keys_in_bucket(C, 5, 5)
+        m = _keys_in_bucket(C, 11, 5)
+        st, applied = h.apply(h.init(), _recs(
+            (1, z, 3, 9, 7),
+            *[(1, key, i % P, 10 + i, 20 + i) for i, key in enumerate(k[:4])],
+            *[(1, key, i % P, 30 + i, 40 + i) for i, key in enumerate(m[:4])]))
+        assert applied.all()
+        st, applied = h.apply(st, _recs((2, k[1], 1, 11, 21)))
+        assert applied.all()
+        keys = {"found": k[0], "after_tombstone": k[2], "deleted": k[1],
+                "missing_tombstoned_window": k[4],
+                "found_full_window_end": m[3], "missing_full_window": m[4],
+                "missing_empty": _keys_in_bucket(C, 2, 1)[0]}
+        return h, st, keys
+
+    @pytest.mark.parametrize("case", [
+        "found", "after_tombstone", "deleted", "missing_tombstoned_window",
+        "found_full_window_end", "missing_full_window", "missing_empty"])
+    def test_lookup_entry_from_probe_window(self, tombstoned, case):
+        """The hash lookup reads node, slot and ctr from its probe window
+        and answers as a re-read of ``idx[pos]`` does (entry 0 on a miss),
+        and as the flat scan does."""
+        h, st, keys = tombstoned
+        key = keys[case]
+        idx = np.asarray(st.idx)[0]
+        C, PROBE = self._LC, self._LPROBE
+        want_found, want_pos = False, 0
+        for j in range(PROBE):
+            p = (int(_np_hash32(key)) % C + j) % C
+            if idx[p, 0] == 0:                  # EMPTY ends the chain
+                break
+            if idx[p, 0] == 1 and idx[p, 1] == key:
+                want_found, want_pos = True, p
+                break
+        assert want_found == case.startswith(("found", "after"))
+        found, pos, node, slot, ctr = h.lookup(st, [key])
+        assert bool(found[0]) == want_found
+        assert int(pos[0]) == want_pos
+        np.testing.assert_array_equal(
+            [int(node[0]), int(slot[0]), int(ctr[0])],
+            idx[want_pos, 2:5].astype(np.int64))
+        if not want_found:
+            assert (int(node[0]), int(slot[0]), int(ctr[0])) == (3, 9, 7)
+        self._pin(h, st, [key])
+
 
 class TestTrackerApplyEquivalence:
     """Vectorized wave scheduler vs the sequential reference sweep on

@@ -10,7 +10,10 @@ Only one process at a time may load libtpu, and every test worker
 imports every test file: the topology is described inside a fixture, in
 the worker that runs this file, never while a module is imported.
 """
+import json
 import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -22,6 +25,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
 from repro.kernels import remote_dma as rdma
 from repro.launch import smoke
 
+ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 GiB = 1 << 30
 V5E_HBM = 16 * GiB
 S, W, N = 131072, smoke.VALUE_WIDTH + 3, 2048   # kvstore rows, P·B lanes
@@ -129,3 +133,28 @@ def test_smoke_window_spreads_over_four_v5e(topo):
     assert per_device == pytest.approx(
         smoke.state_bytes(st) / smoke.FOUR_CHIP_P, rel=0.01)
     assert "all-gather" in compiled.as_text()
+
+
+def test_cell_window_probes_index_in_place(topo):
+    """The benchmark cell ``ycsb_a.p8``'s window (8 nodes on one v5e)
+    reads the index where the entry layout keeps it: no copy of the whole
+    ``st.idx`` parameter to the lane-padded {2,1,0} layout."""
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from bench.store import Store
+    with open(os.path.join(ROOT, "bench", "configs",
+                           "ycsb_1kib_p8.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(ROOT, "bench", "traffic", "ycsb_a.json")) as f:
+        lanes = json.load(f)["lanes_per_participant"]
+    store = Store(cfg, lanes, topo.devices)
+    hlo = store.compile().as_text()
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}\n")]
+    shape = rf"s32\[{store.P},{store.kv.C},5\]"
+    param = re.search(rf"%(\S+) = {shape}\{{[^}}]*\}} parameter\(\d+\)"
+                      r'.*op_name="st\.idx"', entry)
+    assert param, "the window takes the index as a parameter"
+    padded = re.findall(rf"%\S+ = {shape}\{{2,1,0\b[^}}]*\}} "
+                        rf"copy\(%{re.escape(param.group(1))}\)", entry)
+    assert not padded, padded

@@ -214,6 +214,23 @@ def _i2u(x):
     return jax.lax.bitcast_convert_type(jnp.asarray(x, jnp.int32), jnp.uint32)
 
 
+def _run_if(flag, fn, operand):
+    """``fn(operand)`` when the scalar ``flag`` holds, else ``operand``
+    unchanged; ``flag=None`` runs ``fn`` unconditionally.
+
+    A 0/1-iteration ``while_loop``: the skipped step costs nothing on the
+    device, and the carry keeps its buffer, so a whole-table write inside
+    stays in place.  ``flag`` must be uniform across participants (e.g.
+    reduced from a gather over the axis) — under vmap it then stays an
+    unbatched predicate rather than a select over the carry."""
+    if flag is None:
+        return fn(operand)
+    _f, out = jax.lax.while_loop(
+        lambda c: c[0], lambda c: (jnp.zeros((), jnp.bool_), fn(c[1])),
+        (flag, operand))
+    return out
+
+
 class KVStore(Channel):
     def __init__(self, parent, name: str, mgr: Manager, *,
                  slots_per_node: int, value_width: int = 2,
@@ -965,9 +982,11 @@ class KVStore(Channel):
         write_winner (B,) bool — False for an UPDATE whose row write is
         superseded by a later-ticket same-key UPDATE in the same round,
         any_alloc () bool — whether ANY gathered lane allocates a slot
-        (INSERT/MOVE); uniform across participants, so the placed
-        service rounds can skip the allocation round-trip outright for
-        no-allocation windows — the request is folded into this gather).
+        (INSERT/MOVE), any_write () bool — whether ANY gathered lane
+        UPDATEs or DELETEs).  Both flags are uniform across participants,
+        so the service rounds skip the row writes (and the placed path's
+        allocation round-trip) that no lane of the window needs — the
+        request is folded into this gather.
         """
         me = colls.my_id(self.axis)
         B = op.shape[0]
@@ -983,10 +1002,18 @@ class KVStore(Channel):
         later = queued & (g_tick[None, :] > g_tick[:, None])   # [i,j]: j>i
         round_all, winner_all = self._schedule_core(g_key, g_op, g_want,
                                                     queued, later)
-        any_alloc = jnp.any(g_want & ((g_op == INSERT) | (g_op == MOVE)))
+        any_alloc, any_write = self._window_kinds(g_op, g_want)
         return (jax.lax.dynamic_slice(round_all, (me * B,), (B,)),
                 jax.lax.dynamic_slice(winner_all, (me * B,), (B,)),
-                any_alloc)
+                any_alloc, any_write)
+
+    @staticmethod
+    def _window_kinds(g_op, g_want):
+        """(any_alloc, any_write) over the gathered lanes: whether any
+        lock-wanting lane allocates a slot (INSERT/MOVE), and whether any
+        rewrites a live row (UPDATE/DELETE)."""
+        return (jnp.any(g_want & ((g_op == INSERT) | (g_op == MOVE))),
+                jnp.any(g_want & ((g_op == UPDATE) | (g_op == DELETE))))
 
     @staticmethod
     def _schedule_core(g_key, g_op, g_want, queued, later):
@@ -1088,19 +1115,21 @@ class KVStore(Channel):
         round_all, winner_all = self._schedule_core(g_key, g_op, g_want,
                                                     queued, later)
         win_fast = ~jnp.any(g_want & (g_op != UPDATE))
+        any_alloc, any_write = self._window_kinds(g_op, g_want)
         return dict(
             rank=rank, totals=totals,
             round_no=jax.lax.dynamic_slice(round_all, (me * B,), (B,)),
             write_winner=jax.lax.dynamic_slice(winner_all, (me * B,), (B,)),
             win_fast=win_fast,
             any_want=jnp.any(g_want),
-            any_alloc=jnp.any(g_want & ((g_op == INSERT) | (g_op == MOVE))),
+            any_alloc=any_alloc, any_write=any_write,
             inv_node=g[:, 4], inv_slot=g[:, 5], inv_flag=g[:, 6] != 0)
 
     # -- one service round over the whole (B,) window ---------------------------------
     def _service_window(self, st: KVStoreState, op, key, value, lock_id,
                         ticket, pending, look, serve=None,
-                        write_winner=None, homes=None, any_alloc=None):
+                        write_winner=None, homes=None, any_alloc=None,
+                        any_write=None):
         """Vectorized :meth:`_service_round`: every window slot whose lock
         this participant currently holds executes in this round.
 
@@ -1135,6 +1164,14 @@ class KVStore(Channel):
         Service rounds therefore cost the per-lock *conflict depth*, not the
         max queue depth: a window of P·B distinct-key UPDATEs completes in
         ONE round even when a stripe lock queues 30 of them.
+
+        Each of the round's row writes is a pass over the whole table, even
+        with every lane masked, so it runs only when a lane of its kind
+        exists anywhere in the window: the INSERT phases on ``any_alloc``,
+        the UPDATE/DELETE write on ``any_write``.  Both flags come from the
+        schedule gather, so every participant decides alike, and a skipped
+        write is the identity (no lane anywhere would have written a row).
+        ``None`` (the ``reference_impl`` spec path) writes unconditionally.
         """
         if homes is not None:
             return self._service_window_placed(
@@ -1166,10 +1203,15 @@ class KVStore(Channel):
             jnp.clip(st.free_top - 1 - ins_rank, 0, self.S - 1)]
         free_top = st.free_top - jnp.sum(do_ins.astype(jnp.int32))
         new_ctr = st.slot_ctr[my_slot] + jnp.uint32(1)
-        row_invalid = jax.vmap(
-            lambda v, c: self.encode_row(v, c, False))(value, new_ctr)
-        rows_inv = self.rows_region.local_write_batch(
-            st.rows, my_slot, row_invalid, preds=do_ins)
+
+        def write_invalid(rows):
+            row_invalid = jax.vmap(
+                lambda v, c: self.encode_row(v, c, False))(value, new_ctr)
+            return self.rows_region.local_write_batch(
+                rows, my_slot, row_invalid, preds=do_ins)
+
+        with jax.named_scope("kv.insert"):
+            rows_inv = _run_if(any_alloc, write_invalid, st.rows)
         ctr_row = jnp.where(do_ins, my_slot, self.S)          # drop non-lanes
         slot_ctr = st.slot_ctr.at[ctr_row].set(new_ctr, mode="drop")
         st = st._replace(rows=rows_inv, slot_ctr=slot_ctr, free_top=free_top)
@@ -1224,25 +1266,34 @@ class KVStore(Channel):
         # ---- UPDATE / DELETE: every one-sided row write of the round in ONE
         # batched collective (update rows carry (value, same ctr, valid);
         # delete rows clear the payload and unset valid, ctr preserved).
-        row_upd = jax.vmap(
-            lambda v, c: self.encode_row(v, c, True))(value, ctr)
-        row_del = jax.vmap(lambda c: self.encode_row(
-            jnp.zeros((self.W,), jnp.int32), c, False))(ctr)
         # Same-key UPDATEs may co-serve; the schedule precomputed which
         # lane's write survives (last ticket), so superseded lanes are
         # simply masked out and the batch stays collision-free
         # (assume_unique) — no in-loop winner mask needed.
-        rows2, _ = self.rows_region.write_batch(
-            st.rows, node, slot, jnp.where(do_upd[:, None], row_upd, row_del),
-            preds=(do_upd & upd_winner) | do_del, assume_unique=True)
-        st = st._replace(rows=rows2)
+        def write_upd_del(rows):
+            row_upd = jax.vmap(
+                lambda v, c: self.encode_row(v, c, True))(value, ctr)
+            row_del = jax.vmap(lambda c: self.encode_row(
+                jnp.zeros((self.W,), jnp.int32), c, False))(ctr)
+            rows2, _ = self.rows_region.write_batch(
+                rows, node, slot, jnp.where(do_upd[:, None], row_upd,
+                                            row_del),
+                preds=(do_upd & upd_winner) | do_del, assume_unique=True)
+            return rows2
+
+        st = st._replace(rows=_run_if(any_write, write_upd_del, st.rows))
 
         # ---- INSERT phase 2: mark valid **after** every peer acknowledged
-        row_valid = jax.vmap(
-            lambda v, c: self.encode_row(v, c, True))(value, new_ctr)
         gate = join(AckKey(jax.tree.leaves(acks)), ins_ok & all_acked)
-        st = st._replace(rows=self.rows_region.local_write_batch(
-            st.rows, my_slot, row_valid, preds=gate))
+
+        def write_valid(rows):
+            row_valid = jax.vmap(
+                lambda v, c: self.encode_row(v, c, True))(value, new_ctr)
+            return self.rows_region.local_write_batch(
+                rows, my_slot, row_valid, preds=gate)
+
+        with jax.named_scope("kv.insert"):
+            st = st._replace(rows=_run_if(any_alloc, write_valid, st.rows))
 
         # ---- release every lock held this round (effects joined first).
         # The scheduled path defers the now_serving bump to the end of the
@@ -1628,11 +1679,11 @@ class KVStore(Channel):
                                           look0)
                     return (jnp.zeros((), jnp.bool_), p["rank"], p["totals"],
                             p["round_no"], p["write_winner"], p["win_fast"],
-                            p["any_alloc"],
+                            p["any_alloc"], p["any_write"],
                             p["inv_node"], p["inv_slot"], p["inv_flag"])
 
-                (_t, rank, totals, rno, wwin, wfast, aalloc, inode, islot,
-                 iflag) = jax.lax.while_loop(
+                (_t, rank, totals, rno, wwin, wfast, aalloc, awrite, inode,
+                 islot, iflag) = jax.lax.while_loop(
                         lambda c: c[0], pbody,
                         (any_want, jnp.zeros((B,), jnp.uint32),
                          jnp.zeros((self.L,), jnp.uint32),
@@ -1640,12 +1691,14 @@ class KVStore(Channel):
                          jnp.zeros((B,), jnp.bool_),
                          jnp.ones((), jnp.bool_),
                          jnp.zeros((), jnp.bool_),
+                         jnp.zeros((), jnp.bool_),
                          jnp.zeros((N,), jnp.int32),
                          jnp.zeros((N,), jnp.int32),
                          jnp.zeros((N,), jnp.bool_)))
                 plan = dict(rank=rank, totals=totals, round_no=rno,
                             write_winner=wwin, win_fast=wfast,
                             any_want=any_want, any_alloc=aalloc,
+                            any_write=awrite,
                             inv_node=inode, inv_slot=islot, inv_flag=iflag)
         if not lockfree:
             # every acquired ticket completes within this window, so the
@@ -1665,17 +1718,18 @@ class KVStore(Channel):
                 st, keys, ops == GET, look=look0)
 
         if self.reference_impl:
-            round_no, write_winner, any_alloc = None, None, None
+            round_no = write_winner = any_alloc = any_write = None
         elif not lockfree:
             # work-proportional schedule, computed once outside the loop
-            # (the placed path's allocation request rides this gather as
-            # the uniform ``any_alloc`` flag, §14)
+            # (which row writes the rounds need ride this gather as the
+            # uniform ``any_alloc``/``any_write`` flags, §14)
             with jax.named_scope("kv.schedule"):
-                round_no, write_winner, any_alloc = self._service_schedule(
+                (round_no, write_winner, any_alloc,
+                 any_write) = self._service_schedule(
                     ops, keys, lock_id, ticket, want_lock)
 
         def _serve_rounds(st_s, pending0, succ0, ticket, round_no,
-                          write_winner, any_alloc):
+                          write_winner, any_alloc, any_write):
             def cond(c):
                 _st, pending, _succ, _look, _r = c
                 return jax.lax.psum(
@@ -1690,7 +1744,7 @@ class KVStore(Channel):
                             st_c, ops, keys, values, lock_id, ticket,
                             pending, look, serve=serve,
                             write_winner=write_winner, homes=homes,
-                            any_alloc=any_alloc)
+                            any_alloc=any_alloc, any_write=any_write)
                 return st_c, pending, succ | s_now, look, r + 1
 
             return jax.lax.while_loop(
@@ -1744,18 +1798,16 @@ class KVStore(Channel):
                     lambda v, c2: self.encode_row(v, c2, True))(values,
                                                                 ctr0)
 
-                def fbody(fc):
-                    _ft, frows = fc
+                def fast_write(frows):
                     rows2, _ = self.rows_region.write_batch(
                         frows, node0.astype(jnp.int32),
                         slot0.astype(jnp.int32), row_upd,
                         preds=do_upd_fast & plan["write_winner"],
                         assume_unique=True)
-                    return jnp.zeros((), jnp.bool_), rows2
+                    return rows2
 
                 with jax.named_scope("kv.service"):
-                    _ft, rows = jax.lax.while_loop(
-                        lambda fc: fc[0], fbody, (win_fast, rows))
+                    rows = _run_if(win_fast, fast_write, rows)
                 return (jnp.zeros((), jnp.bool_), lstate, cache, rows,
                         ticket, lock_totals)
 
@@ -1771,7 +1823,7 @@ class KVStore(Channel):
             if has_cache:
                 st = st._replace(cache=cache_out)
             round_no, write_winner = plan["round_no"], plan["write_winner"]
-            any_alloc = plan["any_alloc"]
+            any_alloc, any_write = plan["any_alloc"], plan["any_write"]
             pending0, succ0 = want_lock & ~win_fast, do_upd_fast
             if self.mgr.traffic.enabled:
                 colls.record_fastpath(
@@ -1784,7 +1836,7 @@ class KVStore(Channel):
         with jax.named_scope("kv.service"):
             st, _pending, succ, _look, _r = _serve_rounds(
                 st, pending0, succ0, ticket, round_no, write_winner,
-                any_alloc)
+                any_alloc, any_write)
 
         if not self.reference_impl:
             # deferred batched release: critical-section effects joined

@@ -1105,3 +1105,103 @@ class TestReadTier:
         for p in range(P):
             np.testing.assert_array_equal(vals[p, 0], v(1))
             np.testing.assert_array_equal(vals[p, 2], v(3))
+
+
+# ---------------------------- service-round row writes gated on lane kinds
+_gate_kw = dict(slots_per_node=S, value_width=W, num_locks=LOCKS,
+                index_capacity=64)
+gmgr = make_manager(P)
+GATE_STORES = {
+    "locked": KVStore(None, "kv_gate", gmgr, **_gate_kw),
+    "lockfree": KVStore(None, "kv_gate_lf", gmgr, lockfree=True,
+                        **_gate_kw),
+}
+gate_ref = KVStore(None, "kv_gate_ref", gmgr, reference_impl=True,
+                   **_gate_kw)
+
+
+def _lanes(ops_keys, salt=0):
+    """Two lanes per participant from a flat (op, key) list in
+    (participant, lane) order."""
+    flat = [(op, k, v(k, salt)) for op, k in ops_keys]
+    return [flat[2 * p:2 * p + 2] for p in range(P)]
+
+
+SEEDED = _lanes([(INSERT, k) for k in range(1, 9)])
+PROBE = _lanes([(GET, k) for k in range(1, 9)])
+GATE_CASES = {
+    "insert_only": ("locked", [
+        _lanes([(INSERT, k) for k in range(1, 9)]), PROBE]),
+    "update_only": ("locked", [
+        SEEDED, _lanes([(UPDATE, k) for k in range(1, 9)], salt=1), PROBE]),
+    "delete_only": ("locked", [
+        SEEDED, _lanes([(DELETE, k) for k in range(1, 9)]), PROBE]),
+    "get_only": ("locked", [SEEDED, PROBE]),
+    # one participant's one INSERT lane opens the INSERT writes for all
+    "one_insert_lane": ("locked", [
+        SEEDED, _lanes([(UPDATE, 1), (GET, 2), (UPDATE, 3), (GET, 4),
+                        (GET, 5), (INSERT, 9), (UPDATE, 7), (GET, 8)],
+                       salt=2),
+        _lanes([(GET, k) for k in (1, 3, 5, 7, 9, 2, 4, 8)])]),
+    # INSERT behind a DELETE of the same key: a second service round
+    "insert_behind_delete": ("locked", [
+        SEEDED, [[(DELETE, 3, v(3)), NOPR], [(INSERT, 3, v(3, 3)), NOPR]]
+        + [[NOPR, NOPR]] * (P - 2), PROBE]),
+    # a mixed window on the lock-free store takes the locked rounds
+    "lockfree_fallback": ("lockfree", [
+        SEEDED, _lanes([(UPDATE, 1), (UPDATE, 2), (DELETE, 3), (GET, 4),
+                        (UPDATE, 5), (INSERT, 9), (GET, 7), (UPDATE, 8)],
+                       salt=4),
+        _lanes([(GET, k) for k in (1, 2, 3, 5, 9, 4, 7, 8)])]),
+}
+
+
+def _index_view(skv, st, keys=range(1, 17)):
+    """What ``skv``'s own lookup reads for ``keys`` on every participant:
+    found, and (node, slot, ctr) where found."""
+    ks = jnp.broadcast_to(jnp.asarray(list(keys), jnp.uint32),
+                          (P, len(keys)))
+    found, _pos, node, slot, ctr = jax.jit(lambda s, k: gmgr.runtime.run(
+        lambda ss, kk: jax.vmap(lambda q: skv._index_lookup(ss, q))(kk),
+        s, k))(st, ks)
+    found = np.asarray(found)
+    where = np.stack([np.asarray(node), np.asarray(slot),
+                      np.asarray(ctr).astype(np.int64)])
+    return found, np.where(found, where, 0)
+
+
+@pytest.mark.parametrize("case", sorted(GATE_CASES))
+def test_gated_row_writes_match_reference(case):
+    """The service round writes INSERT rows only when some lane of the
+    window inserts, and UPDATE/DELETE rows only when some lane rewrites
+    one: a skipped write is the identity.  Every window commits the same
+    state leaves and answers as the ``reference_impl`` store (which
+    writes unconditionally), and the answers match the oracle.  The index
+    leaf is laid out differently by the reference's flat scan, so it is
+    compared by what each store's own lookup reads from it."""
+    store_name, windows = GATE_CASES[case]
+    store = GATE_STORES[store_name]
+    st, outs = drive_windows(windows, store_mgr=gmgr, store=store)
+    st_r, outs_r = drive_windows(windows, store_mgr=gmgr, store=gate_ref)
+    for la, lb in zip(jax.tree.leaves(st._replace(idx=None)),
+                      jax.tree.leaves(st_r._replace(idx=None))):
+        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
+    for got, want in zip(_index_view(store, st), _index_view(gate_ref, st_r)):
+        np.testing.assert_array_equal(got, want)
+    oracle = Oracle()
+    for rnd, (w, res, res_r) in enumerate(zip(windows, outs, outs_r)):
+        for la, lb in zip(jax.tree.leaves(res), jax.tree.leaves(res_r)):
+            np.testing.assert_array_equal(la, lb, err_msg=f"window {rnd}")
+        expect = oracle.apply_window(w)
+        for p, lane in enumerate(w):
+            for b, (op, key, _val) in enumerate(lane):
+                if op == NOP:
+                    continue
+                if op == GET:
+                    assert bool(res.found[p][b]) == (expect[p][b] is not None)
+                    if expect[p][b] is not None:
+                        np.testing.assert_array_equal(res.value[p][b],
+                                                      expect[p][b])
+                else:
+                    assert bool(res.found[p][b]) == expect[p][b], \
+                        f"window {rnd} p{p}b{b} op{op}({key})"

@@ -10,6 +10,7 @@ Only one process at a time may load libtpu, and every test worker
 imports every test file: the topology is described inside a fixture, in
 the worker that runs this file, never while a module is imported.
 """
+import functools
 import json
 import os
 import re
@@ -135,10 +136,10 @@ def test_smoke_window_spreads_over_four_v5e(topo):
     assert "all-gather" in compiled.as_text()
 
 
-def test_cell_window_probes_index_in_place(topo):
-    """The benchmark cell ``ycsb_a.p8``'s window (8 nodes on one v5e)
-    reads the index where the entry layout keeps it: no copy of the whole
-    ``st.idx`` parameter to the lane-padded {2,1,0} layout."""
+@functools.lru_cache(maxsize=1)
+def _cell_window(topo):
+    """The benchmark cell ``ycsb_a.p8``'s window (8 nodes on one v5e),
+    compiled once for the tests below: (store, HLO text)."""
     if ROOT not in sys.path:
         sys.path.insert(0, ROOT)
     from bench.store import Store
@@ -148,7 +149,27 @@ def test_cell_window_probes_index_in_place(topo):
     with open(os.path.join(ROOT, "bench", "traffic", "ycsb_a.json")) as f:
         lanes = json.load(f)["lanes_per_participant"]
     store = Store(cfg, lanes, topo.devices)
-    hlo = store.compile().as_text()
+    return store, store.compile().as_text()
+
+
+def _computations(hlo):
+    """``{computation: [instruction lines]}`` of an HLO module's text."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%(\S+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and re.match(r"^\s+(?:ROOT )?%\S+ = ", line):
+            comps[name].append(line)
+    return comps
+
+
+def test_cell_window_probes_index_in_place(topo):
+    """The benchmark cell ``ycsb_a.p8``'s window (8 nodes on one v5e)
+    reads the index where the entry layout keeps it: no copy of the whole
+    ``st.idx`` parameter to the lane-padded {2,1,0} layout."""
+    store, hlo = _cell_window(topo)
     entry = hlo[hlo.index("\nENTRY "):]
     entry = entry[:entry.index("\n}\n")]
     shape = rf"s32\[{store.P},{store.kv.C},5\]"
@@ -158,3 +179,46 @@ def test_cell_window_probes_index_in_place(topo):
     padded = re.findall(rf"%\S+ = {shape}\{{2,1,0\b[^}}]*\}} "
                         rf"copy\(%{re.escape(param.group(1))}\)", entry)
     assert not padded, padded
+
+
+def test_cell_window_writes_rows_only_under_their_lanes(topo):
+    """Each whole-table row write of the cell's service round (INSERT
+    phase 1, the UPDATE/DELETE write, INSERT phase 2) sits in a ``while``
+    body of its own, run only when a lane of its kind is in the window:
+    at most one table scatter lies directly in the service loop's body,
+    and the entry computation relays the table no more than four
+    times."""
+    store, hlo = _cell_window(topo)
+    kv = store.kv
+    item = kv.rows_region.item_shape[0]
+    table = (rf"s32\[(?:{item},{store.P * kv.S}|{store.P},{kv.S},{item})\]"
+             r"\{[^}]*\}")
+    comps = _computations(hlo)
+    entry = next(c for c in comps if hlo.count(f"\nENTRY %{c} ") == 1)
+
+    def calls(line):
+        return re.findall(r"\bcalls=%([^\s,]+)", line)
+
+    def scatters(comp):          # the computation or one it fuses
+        return any(" scatter(" in ln or any(scatters(c) for c in calls(ln))
+                   for ln in comps[comp])
+
+    fused = {c for lines in comps.values() for ln in lines for c in calls(ln)}
+    writes = {c: [ln for ln in lines
+                  if re.search(rf"= {table} (?:scatter|fusion)\(", ln)
+                  and (" scatter(" in ln or any(scatters(f)
+                                                for f in calls(ln)))]
+              for c, lines in comps.items() if c not in fused}
+    service = [re.search(r"\bbody=%([^\s,]+)", ln).group(1)
+               for ln in comps[entry] if " while(" in ln
+               and re.search(r'op_name="[^"]*\(kv\.service\)/while"', ln)]
+    assert len(service) == 1, service
+    body = service[0]
+    own = {c: w for c, w in writes.items() if w and c != body}
+    inner = {re.search(r"\bbody=%([^\s,]+)", ln).group(1)
+             for ln in comps[body] if " while(" in ln}
+    assert sum(map(len, writes.values())) == 3, writes
+    assert len(writes[body]) <= 1, writes[body]
+    assert all(len(w) == 1 and c in inner for c, w in own.items()), own
+    copies = re.findall(rf"= {table} copy\(", "\n".join(comps[entry]))
+    assert len(copies) <= 4, copies
